@@ -23,11 +23,10 @@ import argparse   # noqa: E402
 import json       # noqa: E402
 from pathlib import Path  # noqa: E402
 
-import jax        # noqa: E402
-
 from repro.configs import SHAPES, get_config  # noqa: E402
 from repro.core.roofline import cell_roofline  # noqa: E402
 from repro.launch.dryrun import run_cell       # noqa: E402
+from repro.launch.mesh import make_mesh        # noqa: E402
 
 ART = Path(__file__).resolve().parent.parent / "artifacts" / "perf"
 
@@ -110,7 +109,7 @@ def mesh_dict(mesh):
 
 def run_variant(arch, shape_name, name, spec, outdir):
     mesh_spec = spec.get("mesh", ((16, 16), ("data", "model")))
-    mesh = jax.make_mesh(*mesh_spec)
+    mesh = make_mesh(*mesh_spec)
     mb = spec.get("microbatches")
     cfg_over = spec.get("cfg_overrides", {})
     rec = run_cell(arch, shape_name, mesh, f"{mesh_spec[0]}", outdir=None,

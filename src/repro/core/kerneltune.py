@@ -46,9 +46,8 @@ from repro.core.tuner import (ArgminLabeler, SearchSpace, Tuner, TuneQuery,
                               TunerService)
 from repro.kernels.flash_attention import vmem_bytes as fa_vmem
 from repro.kernels.matmul_blocked import vmem_bytes as mm_vmem
-from repro.kernels.timing import DTYPE_BYTES, KernelCase
+from repro.kernels.timing import DTYPE_BYTES, VMEM_BUDGET, KernelCase
 
-VMEM_BUDGET = 16 * 2**20          # ~16 MiB usable VMEM per core (v5e)
 MXU = 128                         # systolic array edge
 
 #: LogStore source tag for backend-measured tile records.  Together with

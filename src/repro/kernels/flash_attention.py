@@ -8,8 +8,8 @@ flash algorithm (no warp-level primitives; the MXU consumes whole
 [block_q, block_k] tiles and the VPU does the rescaling).
 
 (block_q, block_k) are the paper-sense "block size" tuned by
-repro.core.kerneltune: VMEM use = block_q*d + 2*block_k*d + block_q*block_k
-+ fp32 accumulators.
+repro.core.kerneltune: VMEM use = double-buffered q, k, v and o tiles +
+the fp32 block_q*block_k scores and accumulators.
 
 The backward pass recomputes through the jnp reference (custom_vjp): on
 real TPU one would add the flash bwd kernel; correctness and the training
@@ -159,5 +159,8 @@ flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def vmem_bytes(block_q: int, block_k: int, d: int, dtype_bytes: int = 2):
-    return (block_q * d + 2 * block_k * d) * dtype_bytes \
+    """VMEM working set of one grid step: the q, k, v input and o output
+    tiles double-buffered by the pipeline, plus fp32 scores, running
+    max/denominator and output accumulator."""
+    return 2 * (2 * block_q * d + 2 * block_k * d) * dtype_bytes \
         + (block_q * block_k + block_q * d + 2 * block_q) * 4

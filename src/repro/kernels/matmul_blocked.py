@@ -1,9 +1,10 @@
 """Blocked matmul Pallas kernel with explicit BlockSpec VMEM tiling.
 
 The (block_m, block_n, block_k) tile triple is the kernel-level "block
-size" in the paper's sense: it fixes the VMEM working set
-(bm*bk + bk*bn + bm*bn fp32 accum) and the MXU utilization, and is tuned by
-repro.core.kerneltune the same way the paper tunes (p_r, p_c).
+size" in the paper's sense: it fixes the VMEM working set (double-buffered
+bm*bk, bk*bn input and bm*bn output tiles plus the bm*bn fp32 accumulator)
+and the MXU utilization, and is tuned by repro.core.kerneltune the same way
+the paper tunes (p_r, p_c).
 
 Grid = (M/bm, N/bn, K/bk), K innermost (sequential on TPU), accumulating in
 an fp32 VMEM scratch tile that is written out on the last K step.
@@ -57,6 +58,9 @@ def matmul_blocked(a: jax.Array, b: jax.Array, *, block_m: int = 128,
 
 def vmem_bytes(block_m: int, block_n: int, block_k: int,
                dtype_bytes: int = 2) -> int:
-    """VMEM working set of one grid step -- the kernel tuner's OOM check."""
-    return (block_m * block_k + block_k * block_n) * dtype_bytes \
-        + block_m * block_n * 4
+    """VMEM working set of one grid step -- the kernel tuner's OOM check.
+    The pipeline double-buffers both input tiles and the output tile (the
+    next block loads while this one computes); the fp32 accumulator is
+    single scratch."""
+    return 2 * (block_m * block_k + block_k * block_n
+                + block_m * block_n) * dtype_bytes + block_m * block_n * 4

@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Handle padding to block multiples, dtype plumbing, and backend selection
-(``interpret=True`` off-TPU so the kernel bodies execute -- and are tested
--- on CPU).  Block sizes default to MXU-aligned values and may be overridden
+(compiled on a TPU; ``interpret=True`` on the CPU so the kernel bodies
+execute -- and are tested -- there; any other backend is refused).  Block
+sizes default to MXU-aligned values and may be overridden
 by the kernel autotuner (repro.core.kerneltune).
 """
 from __future__ import annotations
@@ -17,7 +18,12 @@ from repro.kernels import matmul_blocked as _mm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on a TPU or interpreted on the "
+            f"CPU; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x, mult, axis):

@@ -7,8 +7,8 @@ implementations:
 
 * :class:`WallClockBackend` — times the actual Pallas kernels
   (``kernels/matmul_blocked.py`` / ``kernels/flash_attention.py``) through
-  the jit'd ``kernels/ops.py`` wrappers: interpret mode off-TPU, compiled
-  on-TPU, warmup then median-of-k repeats, and result-vs-jnp-reference
+  the jit'd ``kernels/ops.py`` wrappers: interpret mode on the CPU,
+  compiled on a TPU, warmup then median-of-k repeats, and result-vs-jnp-reference
   verification so a mis-tiled kernel can never report a fast-but-wrong
   time (a failed verification scores ``inf``).
 * :class:`SimulatorBackend` — a deterministic seeded tile simulator in the
@@ -40,9 +40,16 @@ from repro.kernels.matmul_blocked import vmem_bytes as mm_vmem
 
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
 
-# ~16 MiB usable VMEM per v5e core; a working set over half of it cannot
-# double-buffer, so its load and compute stages serialize
-VMEM_BUDGET = 16 * 2**20
+# Largest double-buffered working set (the kernels' ``vmem_bytes``) a tile
+# may have.  The v5e compiler's scoped-VMEM limit is 16 MiB, and Mosaic adds
+# scratch of its own (fp32 operand copies, the dot result) that the count
+# leaves out.  Compiling the power-of-two tile cube (128..2048 a side) of
+# the yi-6b ffn GEMM for a v5e: every matmul tile counted at or under
+# 10.5 MiB compiled; at 12 MiB (1024, 256, 2048) was refused while
+# (1024, 1024, 512) compiled, and above 13 MiB none did.  Every flash tile
+# (d = 128) up to 12 MiB compiled, and the next size, 21 MiB, was refused.
+# The budget admits only sizes at which every tile compiled.
+VMEM_BUDGET = 11 * 2**20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,8 +107,9 @@ class SimulatorBackend:
 
     Divergence from the closed-form cost model is the whole point: the
     simulator prices per-*step* tile traffic (not whole-matrix refetch),
-    serializes load/compute when the working set is too big to
-    double-buffer, applies an MXU efficiency droop on tiles past 256x256
+    with loads overlapping compute (every tile it is given fits
+    double-buffered: ``feasible_tiles`` prunes the rest before any
+    backend is called), applies an MXU efficiency droop on tiles past 256x256
     (accumulate-pipeline pressure the analytic model ignores), charges a
     heavier per-step launch overhead, and perturbs every reading by a
     seeded +/-``noise_amp``.  Identical seeds give identical times."""
@@ -140,11 +148,6 @@ class SimulatorBackend:
         load_bytes = (bm * bk + bk * bn) * db
         step = float(roofline_time(2.0 * bm * bn * bk * droop, load_bytes,
                                    hw=self.hw, eff=eff))
-        if tile_vmem_bytes(case, bm, bn, bk) > VMEM_BUDGET / 2:
-            # no room to double-buffer: stages serialize instead of overlap
-            step = 2.0 * bm * bn * bk * droop / (self.hw.peak_flops
-                                                 * max(eff, 1e-3)) \
-                + load_bytes / self.hw.hbm_bw
         fill = load_bytes / self.hw.hbm_bw
         writeback = gm * gn * bm * bn * db / self.hw.hbm_bw
         occupancy = 1.25 if steps < 4 else 1.0
@@ -165,9 +168,6 @@ class SimulatorBackend:
         load_bytes = 2 * bk * d * db                      # K and V tiles
         step = float(roofline_time(flops_step, load_bytes, hw=self.hw,
                                    eff=eff))
-        if tile_vmem_bytes(case, bq, bk) > VMEM_BUDGET / 2:
-            step = flops_step / (self.hw.peak_flops * max(eff, 1e-3)) \
-                + load_bytes / self.hw.hbm_bw
         q_io = (bq * d * db) * 2 / self.hw.hbm_bw         # load q, store o
         row = q_io + live * step
         grid_rows = case.batch * case.heads * gq
@@ -193,11 +193,12 @@ class SimulatorBackend:
 
 class WallClockBackend:
     """Times the real Pallas kernels: warmup, then median of ``reps``
-    timed calls, each synchronized with ``block_until_ready``.  Off-TPU
+    timed calls, each synchronized with ``block_until_ready``.  On the CPU
     the kernels run in interpret mode (slow but exact — keep cases small);
     on TPU they compile.  With ``verify=True`` every tile's output is
     checked against the jnp reference oracle first and a mismatch scores
-    ``inf`` — a wrong result must never win the argmin."""
+    ``inf`` — a wrong result must never win the argmin.  ``platform`` and
+    ``device_kind`` name the device the last ``measure`` timed on."""
 
     name = "wallclock"
     deterministic = False
@@ -212,6 +213,7 @@ class WallClockBackend:
         self.measured = 0
         self.verified = 0
         self.verify_failures = 0
+        self.platform = self.device_kind = None
 
     def _arrays(self, case: KernelCase):
         import jax.numpy as jnp
@@ -250,6 +252,8 @@ class WallClockBackend:
         ref = self._reference(case, self._arrays(case)) if self.verify \
             else None
         arrays = self._arrays(case)
+        dev = arrays[0].devices().pop()
+        self.platform, self.device_kind = dev.platform, dev.device_kind
         out = []
         for tile in tiles:
             got = self._call(case, arrays, tile)

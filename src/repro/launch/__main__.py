@@ -19,7 +19,13 @@ initializes) and parse ``sys.argv`` in ``main()``.
 from __future__ import annotations
 
 import importlib
+import os
 import sys
+from pathlib import Path
+
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed path in the checkout, so the next run finds them again
+COMPILE_CACHE = Path(__file__).resolve().parents[3] / "artifacts" / "jax_cache"
 
 # subcommand -> (module, one-line help).  Underscored spellings are
 # accepted as aliases of the dashed ones.
@@ -56,6 +62,20 @@ def _usage(out=None) -> None:
           "launcher's flags.", file=out)
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    other directory is set here; otherwise the cache is ``COMPILE_CACHE``.
+    Call it from an entry point, before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE))
+    return str(COMPILE_CACHE)
+
+
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help", "help"):
@@ -71,6 +91,7 @@ def main(argv=None) -> int:
     # the target must see exactly its own args — both the launchers that
     # argparse sys.argv[1:] and the ones that peek argv at import time
     sys.argv = [f"python -m repro {cmd}"] + argv[1:]
+    use_compile_cache()
     mod = importlib.import_module(module)
     mod.main()
     return 0

@@ -4,17 +4,25 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple):
+    """The one mesh constructor of the repo: every axis ``Auto``.
+
+    The model code places intermediates with ``with_sharding_constraint``
+    and leaves the rest to the partitioner, which needs Auto axes;
+    ``jax.make_mesh``'s default (Explicit) axes make sharding part of every
+    op's type and refuse e.g. the embedding gather."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod ("data","model"); 2 pods adds a "pod" axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple, axes: tuple):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
@@ -25,7 +33,7 @@ def make_host_mesh():
         if n % cand == 0 and cand <= n:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def main(argv=None):

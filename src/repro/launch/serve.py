@@ -19,8 +19,7 @@ import jax            # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np    # noqa: E402
 
-from repro.configs import reduced_config  # noqa: E402
-from repro.launch.train import scale_config, PRESETS  # noqa: E402
+from repro.launch.train import PRESETS, preset_config  # noqa: E402
 from repro.models import transformer as tfm  # noqa: E402
 from repro.models.layers import init_param_tree  # noqa: E402
 
@@ -43,7 +42,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = scale_config(reduced_config(args.arch), **PRESETS[args.preset])
+    cfg, _ = preset_config(args.arch, args.preset)
     params = init_param_tree(tfm.param_specs(cfg), jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
     shape = ((args.batch, cfg.n_codebooks, args.prompt_len)

@@ -20,6 +20,7 @@ import dataclasses
 import jax
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 
 
 class NoFeasibleMeshError(RuntimeError):
@@ -88,7 +89,7 @@ def plan_mesh(n_healthy: int, global_batch: int, *, prefer_model: int = 16,
 
 
 def make_plan_mesh(plan: MeshPlan):
-    return jax.make_mesh(plan.shape, plan.axes)
+    return make_mesh(plan.shape, plan.axes)
 
 
 def reshard_tree(host_tree, shardings):

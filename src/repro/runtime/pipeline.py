@@ -91,6 +91,7 @@ class DataPipeline:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._step = 0
+        self._consumed = self._cursor()
 
     # -------------------------------------------------------------- build
     def _build(self) -> dict:
@@ -120,37 +121,58 @@ class DataPipeline:
         return jax.tree.map(jax.numpy.asarray, batch)
 
     # ------------------------------------------------------------ iterate
+    def _cursor(self) -> dict:
+        return {"batcher": self.batcher.state(), "step": self._step}
+
     def _worker(self):
+        # every batch built is handed out, in order: a full queue makes the
+        # producer wait, never skip
         while not self._stop.is_set():
-            try:
-                self._q.put(self._build(), timeout=0.2)
-            except queue.Full:
-                continue
+            item = (self._build(), self._cursor())
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.2)
+                    break
+                except queue.Full:
+                    continue
 
     def start(self):
         if self._thread is None:
+            self._stop.clear()
             self._thread = threading.Thread(target=self._worker, daemon=True)
             self._thread.start()
         return self
 
     def __next__(self):
         if self._thread is None:
-            return self._put_device(self._build())
-        return self._put_device(self._q.get())
+            batch = self._build()
+            self._consumed = self._cursor()
+        else:
+            batch, self._consumed = self._q.get()
+        return self._put_device(batch)
 
     def __iter__(self):
         return self
 
     def stop(self):
         self._stop.set()
+        if self._thread is not None:
+            self._thread.join()
+            self._thread = None
+        self._q = queue.Queue(maxsize=max(1, self.pcfg.prefetch))
 
     # --------------------------------------------------------- checkpoint
     def state(self) -> dict:
-        # note: with prefetch in flight the persisted state is the producer
-        # cursor; on restore at most `prefetch` batches are re-produced,
-        # which is deterministic and therefore safe.
-        return {"batcher": self.batcher.state(), "step": self._step}
+        """The stream position just past the last batch handed out.  Batches
+        still in the prefetch queue are not counted: after a restore they
+        are produced again, so a resumed run sees every batch once."""
+        return self._consumed
 
     def restore(self, state: dict) -> None:
+        running = self._thread is not None
+        self.stop()                        # drop what was prefetched
         self.batcher.restore(state["batcher"])
         self._step = state["step"]
+        self._consumed = self._cursor()
+        if running:
+            self.start()

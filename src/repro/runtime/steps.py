@@ -90,6 +90,8 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams = TrainHParams(), *,
                     use_flash: bool = False, compress_fn=None,
                     shard_ctx=None):
     """Returns train_step(params, opt_state, batch, step) -> (p, s, metrics).
+    ``metrics["grad_norms"]`` holds each parameter's gradient norm, before
+    clipping, in the tree of the parameters.
 
     ``batch`` leaves carry a leading microbatch axis; gradients accumulate
     across microbatches in ``cfg.grad_accum_dtype`` via ``lax.scan``.
@@ -128,10 +130,13 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams = TrainHParams(), *,
             grads = jax.tree.map(lambda g: g / n_micro, grads)
         if compress_fn is not None:
             grads = compress_fn(grads)
+        grad_norms = jax.tree.map(
+            lambda g: jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32)))),
+            grads)
         new_params, new_state, gnorm = opt_update(cfg, grads, opt_state,
                                                   params, lr)
-        metrics = {"loss": loss, "gnorm": gnorm, "lr": lr,
-                   "step": step.astype(jnp.int32) + 1}
+        metrics = {"loss": loss, "gnorm": gnorm, "grad_norms": grad_norms,
+                   "lr": lr, "step": step.astype(jnp.int32) + 1}
         return new_params, new_state, metrics
 
     return train_step

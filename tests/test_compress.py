@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from _hypothesis_compat import given, settings, st
 
+from repro.launch.mesh import make_mesh
 from repro.runtime.compress import (compress_int8, compress_topk,
                                     dequantize_int8, init_feedback,
                                     quantize_int8, sparse_allreduce,
@@ -70,25 +71,15 @@ def test_int8_error_feedback_converges():
     assert float(jnp.max(jnp.abs(x - t))) < 5e-2
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of experimental after 0.4.x; support both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def test_sparse_allreduce_single_shard():
     """axis of size 1: sparse all-reduce == top-k truncation."""
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     g = jnp.asarray(np.random.default_rng(4).normal(size=(16,)), jnp.float32)
 
-    out = _shard_map(
+    out = jax.shard_map(
         lambda v: sparse_allreduce(v, "x", ratio=0.5),
         mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
-        out_specs=jax.sharding.PartitionSpec())(g)
+        out_specs=jax.sharding.PartitionSpec(), check_vma=False)(g)
     mask = topk_mask(g, 0.5)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(jnp.where(mask, g, 0.0)),

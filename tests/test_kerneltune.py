@@ -27,10 +27,10 @@ from repro.kernels.timing import (KernelCase, SimulatorBackend, get_backend,
 
 # ------------------------------------------------------ feasibility masks
 def test_matmul_mask_tile_exactly_at_budget_is_feasible():
-    # mm_vmem(1024, 1024, 3072, db=2) = 4*1024*(1024+3072) = VMEM_BUDGET
-    assert mm_vmem(1024, 1024, 3072, 2) == VMEM_BUDGET
-    t_at = float(matmul_tile_times(4096, 4096, 4096, 1024, 1024, 3072))
-    t_over = float(matmul_tile_times(4096, 4096, 4096, 1024, 1024, 3073))
+    # mm_vmem(512, 512, 2304, db=2) = 4096*2304 + 2 MiB = 11 MiB = VMEM_BUDGET
+    assert mm_vmem(512, 512, 2304, 2) == VMEM_BUDGET
+    t_at = float(matmul_tile_times(4096, 4096, 4096, 512, 512, 2304))
+    t_over = float(matmul_tile_times(4096, 4096, 4096, 512, 512, 2305))
     assert math.isfinite(t_at)            # mask is strict `> budget`
     assert math.isinf(t_over)             # one element over -> OOM
 
@@ -44,8 +44,9 @@ def test_flash_mask_tracks_its_vmem_formula():
 
 def test_mask_non_power_of_two_remainder_tiles():
     # 1536 = 1024 + 512 remainder; ceil grids must stay finite, overhang inf
+    # (bn = 512: a (1024, 1024, 512) tile is over the VMEM budget)
     assert math.isfinite(
-        float(matmul_tile_times(1536, 1536, 1536, 1024, 1024, 512)))
+        float(matmul_tile_times(1536, 1536, 1536, 1024, 512, 512)))
     assert math.isinf(
         float(matmul_tile_times(1536, 1536, 1536, 2048, 1024, 512)))
     # non-pow2 tile itself (96 is not MXU-aligned but is legal)
@@ -55,7 +56,7 @@ def test_mask_non_power_of_two_remainder_tiles():
 
 def test_mask_dtype_bytes_variants():
     # feasible in bf16, over budget in fp32: working set scales with db
-    tile = (1024, 1024, 3072)
+    tile = (512, 512, 1536)
     assert mm_vmem(*tile, 2) <= VMEM_BUDGET < mm_vmem(*tile, 4)
     assert math.isfinite(float(matmul_tile_times(
         4096, 4096, 4096, *tile, dtype_bytes=2)))
@@ -67,6 +68,17 @@ def test_mask_dtype_bytes_variants():
     assert tile in feasible_tiles(bf16, [tile])
     assert feasible_tiles(fp32, [tile]) == []
     assert tile_vmem_bytes(fp32, *tile) == mm_vmem(*tile, 4)
+
+
+@pytest.mark.parametrize("tile", [(1024, 1024, 1024), (2048, 1024, 512),
+                                  (2048, 512, 512), (1024, 256, 2048)])
+def test_tiles_the_compiler_refuses_are_infeasible(tile):
+    # the v5e compiler runs out of scoped VMEM on these bf16 tiles of the
+    # yi-6b ffn GEMM (tests/test_tpu_compile.py); counting one buffer per
+    # operand had admitted them
+    case = KernelCase("matmul", 4096, 4096, 11008)
+    assert feasible_tiles(case, [tile]) == []
+    assert math.isinf(float(matmul_tile_times(4096, 4096, 11008, *tile)))
 
 
 def test_feasible_tiles_budget_boundary_inclusive():

@@ -107,3 +107,23 @@ def test_deepseek_v3_stage_split():
     stages = tf.build_stages(get_config("deepseek-v3-671b"))
     assert [(len(s.unit), s.repeat) for s in stages] == [(1, 3), (1, 58)]
     assert not stages[0].unit[0].moe and stages[1].unit[0].moe
+
+
+def test_embedding_gradient_sums_repeated_ids_in_float32():
+    # one id repeated 4096 times, as a frequent token repeats in Zipfian
+    # text: a bf16 scatter-add of the gradient would stall far below the
+    # sum once it is ~256x larger than each term
+    table = jax.random.normal(jax.random.PRNGKey(0), (64, 8), jnp.bfloat16)
+    ids = jnp.concatenate([jnp.zeros(4096, jnp.int32), jnp.arange(64)])
+    ct = jax.random.normal(jax.random.PRNGKey(1), (ids.size, 8)) ** 2
+
+    def grad(f, t):
+        return jax.grad(lambda t: jnp.sum(f(t, ids).astype(jnp.float32)
+                                          * ct))(t)
+
+    want = np.asarray(grad(lambda t, i: t[i], table.astype(jnp.float32)))
+    got = np.asarray(grad(tf.gather_rows, table), np.float32)
+    plain = np.asarray(grad(lambda t, i: jnp.take(t, i, axis=0), table),
+                       np.float32)
+    np.testing.assert_allclose(got, want, rtol=1e-2)
+    assert np.abs(plain[0] - want[0]).max() > 0.1 * np.abs(want[0]).max()

@@ -1,4 +1,6 @@
 """Data pipeline: determinism, checkpoint/restore resume, packing, shapes."""
+import time
+
 import numpy as np
 
 from repro.configs import ShapeConfig, reduced_config
@@ -6,10 +8,15 @@ from repro.runtime.pipeline import (DataPipeline, PackedBatcher,
                                     PipelineConfig, SyntheticCorpus)
 
 
-def mk(seed=0, mb=2, batch=4, seq=32):
+def mk(seed=0, mb=2, batch=4, seq=32, prefetch=2):
     cfg = reduced_config("yi-6b").replace(train_microbatches=mb)
     shape = ShapeConfig("t", "train", seq, batch)
-    return DataPipeline(cfg, shape, PipelineConfig(seed=seed))
+    return DataPipeline(cfg, shape, PipelineConfig(seed=seed,
+                                                   prefetch=prefetch))
+
+
+def tokens(p, n):
+    return [np.asarray(next(p)["tokens"]) for _ in range(n)]
 
 
 def test_shapes():
@@ -55,6 +62,51 @@ def test_prefetch_thread():
         assert len(xs) == 3
     finally:
         p.stop()
+
+
+def test_prefetch_thread_hands_out_every_batch_in_order():
+    want = tokens(mk(seed=2), 4)
+    p = mk(seed=2, prefetch=1).start()
+    try:
+        got = []
+        for _ in range(4):
+            got.extend(tokens(p, 1))
+            time.sleep(0.3)            # a consumer slower than the producer
+    finally:
+        p.stop()
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_prefetch_state_is_the_consumer_cursor():
+    want = tokens(mk(seed=4), 5)
+    p = mk(seed=4).start()
+    try:
+        tokens(p, 3)
+        time.sleep(0.2)                # let the producer run ahead
+        state = p.state()
+    finally:
+        p.stop()
+    q = mk(seed=4)
+    q.restore(state)
+    for a, b in zip(want[3:], tokens(q, 2)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_restore_on_a_running_pipeline_drops_the_prefetched():
+    want = tokens(mk(seed=5), 3)
+    p = mk(seed=5).start()
+    try:
+        tokens(p, 1)
+        state = p.state()
+        tokens(p, 2)
+        time.sleep(0.2)
+        p.restore(state)
+        got = tokens(p, 2)
+    finally:
+        p.stop()
+    for a, b in zip(want[1:], got):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_vlm_batch_has_image_embeds():

@@ -25,10 +25,7 @@ def xla_forward_flops(cfg, B, T):
         return logits
 
     c = jax.jit(fwd).lower(pspecs, toks).compile()
-    ca = c.cost_analysis()
-    if isinstance(ca, list):               # jax<=0.4.x: one dict per device
-        ca = ca[0]
-    return ca["flops"]
+    return c.cost_analysis()["flops"]
 
 
 @pytest.mark.parametrize("arch", ["yi-6b", "mixtral-8x7b", "mamba2-370m",

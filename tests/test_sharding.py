@@ -7,14 +7,11 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
 from repro.runtime.sharding import make_rules, resolve_pspec
-
-MESH = jax.make_mesh((1, 1), ("data", "model"))  # names only; size-1 axes
 
 
 class FakeMesh:
@@ -85,7 +82,8 @@ def test_subprocess_8dev_mini_dryrun():
         from repro.runtime import sharding as shd
         from repro.runtime.optim import opt_state_specs
         from repro.runtime.steps import input_specs, step_fn_for
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         for arch in ["yi-6b", "mixtral-8x7b", "mamba2-370m", "hymba-1.5b"]:
             cfg = reduced_config(arch).replace(train_microbatches=2)

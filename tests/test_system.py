@@ -12,7 +12,7 @@ def test_train_loss_improves(tmp_path):
         "--steps", "14", "--ckpt-every", "7", "--quiet",
         "--ckpt-dir", str(tmp_path / "ck"), "--global-batch", "8",
         "--seq", "64",
-    ])
+    ])["loss"]
     assert np.mean(losses[-4:]) < np.mean(losses[:4])
 
 
@@ -22,7 +22,7 @@ def test_train_resume_from_checkpoint(tmp_path):
                 "--ckpt-dir", ck, "--global-batch", "8", "--seq", "64"])
     losses = train_main(["--steps", "12", "--ckpt-every", "4", "--quiet",
                          "--resume", "--ckpt-dir", ck,
-                         "--global-batch", "8", "--seq", "64"])
+                         "--global-batch", "8", "--seq", "64"])["loss"]
     assert len(losses) == 4                     # resumed at 8, ran to 12
 
 
@@ -31,7 +31,7 @@ def test_failure_injection_recovers(tmp_path):
         "--steps", "12", "--ckpt-every", "4", "--inject-failure", "6",
         "--quiet", "--ckpt-dir", str(tmp_path / "ck"),
         "--global-batch", "8", "--seq", "64",
-    ])
+    ])["loss"]
     # restored to step 4 then re-ran: more recorded steps than 12
     assert len(losses) >= 12
     assert np.mean(losses[-4:]) < np.mean(losses[:4])
