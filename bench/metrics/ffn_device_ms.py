@@ -1,0 +1,9 @@
+"""Device time per window step of the step program's ``ffn`` scope: the feed-
+forward block (MLP or MoE), in all phases (forward, backward, remat's
+recompute). ``bench/scopes.py`` names the trace's ops by the compiled
+module."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.part_ms(run, "ffn")

@@ -67,11 +67,17 @@ def use_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
     other directory is set here; otherwise the cache is ``COMPILE_CACHE``.
-    Call it from an entry point, before the first compile."""
+    Call it from an entry point, before the first compile.
+
+    The cache key includes the programs' metadata: by default JAX leaves it
+    out, and a program loaded from the cache then carries the name stacks
+    (the step's named scopes, ``repro.runtime.obs.program_text``) of
+    whichever version of the code compiled it first."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE))
     return str(COMPILE_CACHE)
 

@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import SHAPES, ShapeConfig, get_config, reduced_config  # noqa: E402
 from repro.models import transformer as tfm  # noqa: E402
 from repro.models.layers import init_param_tree, spec_tree_to_sds  # noqa: E402
+from repro.runtime import obs  # noqa: E402
 from repro.runtime import sharding as shd  # noqa: E402
 from repro.runtime.checkpoint import CheckpointManager  # noqa: E402
 from repro.runtime.elastic import adapt_config, make_plan_mesh, plan_mesh  # noqa: E402
@@ -112,6 +113,7 @@ def build(cfg, shape, mesh, hp):
         spec_tree_to_sds(pspecs), spec_tree_to_sds(ospecs),
         spec_tree_to_sds(bspecs), jax.ShapeDtypeStruct((), jnp.int32)).compile()
     compile_s = time.perf_counter() - t0
+    obs.note_program(compiled)
     return compiled, (pspecs, ospecs), (p_sh, o_sh, b_sh), compile_s
 
 

@@ -159,6 +159,7 @@ def param_specs(cfg: ModelConfig):
 # Layer forward (full-sequence)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def _attn_forward(cfg, desc, p, h, positions, n_meta, collect, use_flash):
     if cfg.mla is not None:
         if collect:
@@ -230,10 +231,11 @@ def layer_forward(cfg, desc, p, x, positions, n_meta, *, collect=False,
                 entry["k"], entry["v"] = k, v
 
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if desc.moe:
-        y, aux = moe_mod.moe_apply(cfg, p["ffn"], h2, cfg.moe.router)
-    else:
-        y = mlp(p["ffn"], h2, cfg.act)
+    with jax.named_scope("ffn"):
+        if desc.moe:
+            y, aux = moe_mod.moe_apply(cfg, p["ffn"], h2, cfg.moe.router)
+        else:
+            y = mlp(p["ffn"], h2, cfg.act)
     return x + y, entry, aux
 
 
@@ -348,6 +350,7 @@ def _gather_rows_bwd(res, ct):
 gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, params, tokens):
     if cfg.n_codebooks > 1:                       # musicgen: [B,K,T], table [K,V,D]
         x = sum(gather_rows(params["tok_emb"][k], tokens[:, k])
@@ -359,6 +362,7 @@ def embed_tokens(cfg: ModelConfig, params, tokens):
     return x
 
 
+@jax.named_scope("head_loss")       # shared with the train loss
 def lm_head(cfg: ModelConfig, params, x):
     if cfg.tie_embeddings:
         out = jnp.einsum("btd,vd->btv", x, params["tok_emb"])
@@ -453,12 +457,13 @@ def train_loss(cfg: ModelConfig, params, batch, use_flash=False):
     tokens = batch["tokens"]
     logits, hidden, _, aux, n_prefix = model_forward(
         cfg, params, tokens, batch.get("image_embeds"), use_flash=use_flash)
-    if cfg.n_codebooks > 1:
-        losses = [cross_entropy(logits[:, :-1, k], tokens[:, k, 1:])
-                  for k in range(cfg.n_codebooks)]
-        loss = sum(losses) / cfg.n_codebooks
-    else:
-        loss = cross_entropy(logits[:, :-1], tokens[:, 1:])
+    with jax.named_scope("head_loss"):
+        if cfg.n_codebooks > 1:
+            losses = [cross_entropy(logits[:, :-1, k], tokens[:, k, 1:])
+                      for k in range(cfg.n_codebooks)]
+            loss = sum(losses) / cfg.n_codebooks
+        else:
+            loss = cross_entropy(logits[:, :-1], tokens[:, 1:])
     metrics = {"ce": loss}
     if cfg.moe is not None:
         loss = loss + cfg.moe_aux_coef * aux

@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.runtime import obs
 
 
 @dataclass(frozen=True)
@@ -144,12 +145,14 @@ class DataPipeline:
         return self
 
     def __next__(self):
-        if self._thread is None:
-            batch = self._build()
-            self._consumed = self._cursor()
-        else:
-            batch, self._consumed = self._q.get()
-        return self._put_device(batch)
+        with obs.span("pipeline.wait"):
+            if self._thread is None:
+                batch = self._build()
+                self._consumed = self._cursor()
+            else:
+                batch, self._consumed = self._q.get()
+        with obs.span("pipeline.put"):
+            return self._put_device(batch)
 
     def __iter__(self):
         return self

@@ -115,26 +115,31 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams = TrainHParams(), *,
             loss, grads = micro_grads(params, mb)
         else:
             acc_dt = jnp.dtype(cfg.grad_accum_dtype)
-            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt), params)
+            with jax.named_scope("grad_accum"):
+                zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt),
+                                     params)
 
             def body(carry, mb):
                 gacc, lsum = carry
                 loss, g = micro_grads(params, mb)
-                gacc = jax.tree.map(
-                    lambda a, b: a + b.astype(acc_dt), gacc, g)
+                with jax.named_scope("grad_accum"):
+                    gacc = jax.tree.map(
+                        lambda a, b: a + b.astype(acc_dt), gacc, g)
                 return (gacc, lsum + loss), ()
 
             (grads, lsum), _ = jax.lax.scan(body, (zeros, jnp.zeros((), jnp.float32)),
                                             batch)
-            loss = lsum / n_micro
-            grads = jax.tree.map(lambda g: g / n_micro, grads)
+            with jax.named_scope("grad_accum"):
+                loss = lsum / n_micro
+                grads = jax.tree.map(lambda g: g / n_micro, grads)
         if compress_fn is not None:
             grads = compress_fn(grads)
-        grad_norms = jax.tree.map(
-            lambda g: jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32)))),
-            grads)
-        new_params, new_state, gnorm = opt_update(cfg, grads, opt_state,
-                                                  params, lr)
+        with jax.named_scope("optimizer"):
+            grad_norms = jax.tree.map(
+                lambda g: jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32)))),
+                grads)
+            new_params, new_state, gnorm = opt_update(cfg, grads, opt_state,
+                                                      params, lr)
         metrics = {"loss": loss, "gnorm": gnorm, "grad_norms": grad_norms,
                    "lr": lr, "step": step.astype(jnp.int32) + 1}
         return new_params, new_state, metrics
