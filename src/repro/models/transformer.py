@@ -350,9 +350,24 @@ def _gather_rows_bwd(res, ct):
 gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
-@jax.named_scope("embed")
-def embed_tokens(cfg: ModelConfig, params, tokens):
+def embedding_index(cfg: ModelConfig, tokens):
+    """The index of the rows of ``tok_emb`` that ``tokens`` look up:
+    ``tok_emb[idx]`` is [B,T,D], or [B,K,T,D] with a row per codebook."""
     if cfg.n_codebooks > 1:                       # musicgen: [B,K,T], table [K,V,D]
+        return (jnp.arange(cfg.n_codebooks)[:, None], tokens)
+    return (tokens,)
+
+
+@jax.named_scope("embed")
+def embed_tokens(cfg: ModelConfig, params, tokens, rows=None):
+    """The input embeddings of ``tokens``.  ``rows`` are the table's rows
+    ``tok_emb[embedding_index(cfg, tokens)]`` where the caller gathered
+    them (and takes their gradient itself); else ``gather_rows`` gathers
+    them here."""
+    if rows is not None:
+        x = (sum(rows[:, k] for k in range(cfg.n_codebooks))
+             if cfg.n_codebooks > 1 else rows)
+    elif cfg.n_codebooks > 1:
         x = sum(gather_rows(params["tok_emb"][k], tokens[:, k])
                 for k in range(cfg.n_codebooks))
     else:
@@ -379,9 +394,10 @@ def lm_head(cfg: ModelConfig, params, x):
 # ---------------------------------------------------------------------------
 
 def model_forward(cfg: ModelConfig, params, tokens, image_embeds=None, *,
-                  collect=False, use_flash=False):
-    """Returns (logits, hidden, caches, aux)."""
-    x = embed_tokens(cfg, params, tokens)
+                  collect=False, use_flash=False, rows=None):
+    """Returns (logits, hidden, caches, aux).  ``rows``: as in
+    ``embed_tokens``."""
+    x = embed_tokens(cfg, params, tokens, rows)
     n_prefix = 0
     if cfg.frontend == "vision" and image_embeds is not None:
         img = image_embeds.astype(x.dtype) @ params["img_proj"]
@@ -436,11 +452,11 @@ def decode_step(cfg: ModelConfig, params, cache, tokens_new):
     return logits, {"stages": tuple(new_stage_caches), "pos": pos + 1}
 
 
-def _mtp_loss(cfg, params, hidden, tokens, n_prefix):
+def _mtp_loss(cfg, params, hidden, tokens, n_prefix, rows=None):
     """DeepSeek-V3 multi-token prediction (depth 1) auxiliary loss."""
     mp = params["mtp"]
     h = hidden[:, n_prefix:]                      # [B,T,D] text region
-    emb = embed_tokens(cfg, params, tokens)
+    emb = embed_tokens(cfg, params, tokens, rows)
     h_in = jnp.concatenate(
         [rms_norm(h[:, :-1], mp["ln_h"], cfg.norm_eps),
          rms_norm(emb[:, 1:], mp["ln_e"], cfg.norm_eps)], axis=-1) @ mp["proj"]
@@ -452,11 +468,14 @@ def _mtp_loss(cfg, params, hidden, tokens, n_prefix):
     return cross_entropy(logits[:, :-1], tokens[:, 2:])
 
 
-def train_loss(cfg: ModelConfig, params, batch, use_flash=False):
-    """batch: {"tokens": [B,T] | [B,K,T], "image_embeds"?: [B,P,D]}."""
+def train_loss(cfg: ModelConfig, params, batch, use_flash=False, rows=None):
+    """batch: {"tokens": [B,T] | [B,K,T], "image_embeds"?: [B,P,D]}.
+    ``rows``: as in ``embed_tokens``; with them an untied ``tok_emb`` may be
+    left out of ``params``."""
     tokens = batch["tokens"]
     logits, hidden, _, aux, n_prefix = model_forward(
-        cfg, params, tokens, batch.get("image_embeds"), use_flash=use_flash)
+        cfg, params, tokens, batch.get("image_embeds"), use_flash=use_flash,
+        rows=rows)
     with jax.named_scope("head_loss"):
         if cfg.n_codebooks > 1:
             losses = [cross_entropy(logits[:, :-1, k], tokens[:, k, 1:])
@@ -469,7 +488,7 @@ def train_loss(cfg: ModelConfig, params, batch, use_flash=False):
         loss = loss + cfg.moe_aux_coef * aux
         metrics["aux"] = aux
     if cfg.mtp_depth:
-        mtp = _mtp_loss(cfg, params, hidden, tokens, n_prefix)
+        mtp = _mtp_loss(cfg, params, hidden, tokens, n_prefix, rows)
         loss = loss + cfg.mtp_loss_weight * mtp
         metrics["mtp"] = mtp
     metrics["loss"] = loss

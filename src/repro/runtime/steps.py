@@ -97,14 +97,43 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams = TrainHParams(), *,
     across microbatches in ``cfg.grad_accum_dtype`` via ``lax.scan``.
     ``compress_fn`` optionally transforms the accumulated gradient tree
     (gradient compression; see runtime/compress.py).
+
+    With a float32 accumulator the token embedding's gradient is taken with
+    respect to the rows the microbatch gathers and scatter-added into the
+    accumulator row by row: the table's dense gradient, a zero-filled
+    table per microbatch added whole, is never formed (a tied table still
+    gets its head's dense gradient).  A bfloat16 scatter-add would lose a
+    repeated id's sum (``tf.gather_rows``), and a single microbatch has no
+    accumulator: those take the table's dense gradient.
     """
     n_micro = cfg.train_microbatches
+    acc_dt = jnp.dtype(cfg.grad_accum_dtype)
+    scatter_rows = acc_dt == jnp.float32
 
     def micro_grads(params, mb):
         def loss_fn(p):
             return tf.train_loss(cfg, p, mb, use_flash=use_flash)
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         return loss, grads
+
+    def micro_row_grads(params, mb):
+        """The loss, the gradients of the parameters but an untied
+        ``tok_emb``, and the float32 gradient of the rows gathered from
+        ``tok_emb`` with their index."""
+        table = params["tok_emb"]
+        idx = tf.embedding_index(cfg, mb["tokens"])
+        with jax.named_scope("embed"):   # f32 rows: their gradient comes in f32
+            rows = table[idx].astype(acc_dt)
+
+        def loss_fn(p, rows):
+            with jax.named_scope("embed"):
+                x0 = rows.astype(table.dtype)
+            return tf.train_loss(cfg, p, mb, use_flash=use_flash, rows=x0)
+        dp = params if cfg.tie_embeddings else \
+            {k: v for k, v in params.items() if k != "tok_emb"}
+        (loss, metrics), (grads, drows) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1), has_aux=True)(dp, rows)
+        return loss, grads, (idx, drows)
 
     def train_step(params, opt_state, batch, step):
       with _maybe_scope(shard_ctx):
@@ -114,17 +143,23 @@ def make_train_step(cfg: ModelConfig, hp: TrainHParams = TrainHParams(), *,
             mb = jax.tree.map(lambda x: x[0], batch)
             loss, grads = micro_grads(params, mb)
         else:
-            acc_dt = jnp.dtype(cfg.grad_accum_dtype)
             with jax.named_scope("grad_accum"):
                 zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt),
                                      params)
 
             def body(carry, mb):
                 gacc, lsum = carry
-                loss, g = micro_grads(params, mb)
+                if scatter_rows:
+                    loss, g, (idx, drows) = micro_row_grads(params, mb)
+                else:
+                    loss, g = micro_grads(params, mb)
                 with jax.named_scope("grad_accum"):
-                    gacc = jax.tree.map(
-                        lambda a, b: a + b.astype(acc_dt), gacc, g)
+                    gacc = {k: jax.tree.map(lambda x, y: x + y.astype(acc_dt),
+                                            a, g[k]) if k in g else a
+                            for k, a in gacc.items()}
+                if scatter_rows:
+                    with jax.named_scope("embed"):
+                        gacc["tok_emb"] = gacc["tok_emb"].at[idx].add(drows)
                 return (gacc, lsum + loss), ()
 
             (grads, lsum), _ = jax.lax.scan(body, (zeros, jnp.zeros((), jnp.float32)),
