@@ -34,8 +34,8 @@ def readings(cell, seed: int, modes, devices) -> list:
 
     def ref_run(**kw):
         t0 = time.perf_counter()
-        out = reference.run(cell.config, cell.mix, seed, stream,
-                            len(devices), **kw)
+        out = reference.run(cell.family, cell.config, cell.mix, seed,
+                            stream, len(devices), **kw)
         return out, time.perf_counter() - t0
 
     ref, ref_s = ref_run()

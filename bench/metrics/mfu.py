@@ -1,5 +1,6 @@
-"""Model FLOP utilization of the window, in percent: ``bench/flops.py``'s
-FLOPs per step x steps / (window seconds x chips x the chip's bf16 peak)."""
+"""Model FLOP utilization of the window, in percent: the family's FLOPs per
+step (``step_flops`` of ``bench/families/<family>.py``) x steps / (window
+seconds x chips x the chip's bf16 peak)."""
 
 
 def read(run):

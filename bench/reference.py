@@ -1,29 +1,22 @@
-"""Plain float32 reference of the first two AdamW train steps of a LLaMA-style
-dense decoder (Yi-6B, DeepSeek-LLM-7B), independent of the program.
+"""Plain float32 reference of the first two AdamW train steps, independent of
+the program.  The architecture is the configuration's family
+(``bench/families/<family>.py``: its leaves, which of them decay, and one
+microbatch's loss); this module is the driver every family shares, and the
+pieces a family's loss is built from.
 
-It follows the published architecture: token embedding, then per layer
-``x += Attn(RMSNorm(x))`` with rotary positions (rotate-half, inverse
-frequencies ``theta ** (-2i / head_dim)``) and causal grouped-query
-attention, ``x += W_down(silu(W_gate h) * W_up h)`` with ``h =
-RMSNorm(x)``, a final RMSNorm and an untied LM head; the loss is the mean
-next-token cross-entropy.  Departures, each for the comparison's sake:
+Departures from the published recipe, each for the comparison's sake:
 
-* RMSNorm weights are stored as ``w`` with scale ``1 + w`` (the trainer's
-  layout), so ``w = 0`` is the published initial scale of 1;
 * weights are held as bfloat16 values, as the configuration states; each
   update is computed in float32 and rounded to bfloat16;
-* AdamW (b1 0.9, b2 0.95, eps 1e-8, weight decay 0.1 on matrices, global
-  gradient clipping at 1.0) and the cosine schedule are the training
-  recipe's, read from the traffic mix.
+* AdamW (b1 0.9, b2 0.95, eps 1e-8, weight decay 0.1 on the family's
+  matrices, global gradient clipping at 1.0) and the cosine schedule are
+  the training recipe's, read from the traffic mix.
 
-Leaves are named as the trainer names its checkpoint leaves
-(``stages/0/u0/attn/wq``: one stage of ``num_hidden_layers`` alike layers),
-and every layer is tensor-parallel over a ``model`` axis of as many devices
-as the cell has chips, Megatron style: heads, the FFN's hidden units and
-the vocabulary are split, and the embedding, the attention output and the
-FFN output are summed across the axis.  Matmuls run at ``HIGHEST``
-precision.  ``precision="fp8"`` is the control: every matmul operand,
-forward and backward, rounded to float8_e4m3 under a per-tensor scale.
+Every leaf is laid out over a ``model`` axis of as many devices as the cell
+has chips, by the family's ``PartitionSpec``; the loss runs per shard under
+``shard_map``.  Matmuls run at ``HIGHEST`` precision.  ``precision="fp8"``
+is the control: every matmul operand, forward and backward, rounded to
+float8_e4m3 under a per-tensor scale.
 """
 from __future__ import annotations
 
@@ -35,57 +28,18 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
-STAGE = "stages/0/u0/"
 FP8_MAX = 448.0                        # largest float8_e4m3fn value
 ATTN_BLOCK = 512                       # query rows per attention block
 
 
 # ------------------------------------------------------------------ layout
 
-def specs(cfg: dict) -> dict:
-    """name -> (shape, dtype, init std, PartitionSpec over ``model``)."""
-    d, h, kv = cfg["hidden_size"], cfg["num_attention_heads"], \
-        cfg["num_key_value_heads"]
-    hd, f, v = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
-    n = cfg["num_hidden_layers"]
-    dt = cfg["torch_dtype"]
-
-    def w(fan_in):
-        return min(0.02, fan_in ** -0.5)
-
-    out = {
-        "tok_emb": ((v, d), dt, 0.02, P("model", None)),
-        "final_norm": ((d,), dt, 0.0, P()),
-        "head": ((d, v), dt, w(d), P(None, "model")),
-    }
-    layer = {
-        "ln1": ((n, d), 0.0, P()),
-        "attn/wq": ((n, d, h, hd), w(d), P(None, None, "model", None)),
-        "attn/wk": ((n, d, kv, hd), w(d), P(None, None, "model", None)),
-        "attn/wv": ((n, d, kv, hd), w(d), P(None, None, "model", None)),
-        "attn/wo": ((n, h, hd, d), w(h * hd), P(None, "model", None, None)),
-        "ln2": ((n, d), 0.0, P()),
-        "ffn/wg": ((n, d, f), w(d), P(None, None, "model")),
-        "ffn/wi": ((n, d, f), w(d), P(None, None, "model")),
-        "ffn/wo": ((n, f, d), w(f), P(None, "model", None)),
-    }
-    for k, (shape, std, spec) in layer.items():
-        out[STAGE + k] = (shape, dt, std, spec)
-    return out
-
-
-def is_matrix(name: str) -> bool:
-    """Weight decay applies to matrices: not to the RMSNorm weights."""
-    return not (name.endswith("ln1") or name.endswith("ln2")
-                or name == "final_norm")
-
-
 def make_mesh(chips: int) -> Mesh:
     return Mesh(np.array(jax.devices()[:chips]), ("model",))
 
 
-def shardings(cfg: dict, mesh: Mesh) -> dict:
-    return {n: NamedSharding(mesh, s[3]) for n, s in specs(cfg).items()}
+def shardings(fam, cfg: dict, mesh: Mesh) -> dict:
+    return {n: NamedSharding(mesh, s[3]) for n, s in fam.specs(cfg).items()}
 
 
 # ------------------------------------------------------------------ model
@@ -120,12 +74,12 @@ def _einsum_fp8_bwd(spec, res, ct):
 _einsum_fp8.defvjp(_einsum_fp8_fwd, _einsum_fp8_bwd)
 
 
-def _rms(x, w, eps):
+def rms_norm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
         * (1.0 + w)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     t, hd = x.shape[1], x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
@@ -134,7 +88,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, ein):
+def attention(q, k, v, ein):
     """Causal softmax attention, a block of query rows at a time (each
     block recomputed in the backward pass) so the scores fit."""
     b, t, h, hd = q.shape
@@ -157,38 +111,22 @@ def _attention(q, k, v, ein):
     return out.transpose(1, 0, 2, 3, 4).reshape(b, t, h, hd)
 
 
-def _loss(cfg, p, tokens, ein, exchange):
-    """Mean next-token cross-entropy of one microbatch; runs per shard."""
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
+def embed(emb, tokens, reduce):
+    """Rows of the token embedding, whose table is split by rows over the
+    ``model`` axis: each shard gives the rows it holds and zeros for the
+    rest, and ``reduce`` sums the shards' parts."""
     i = jax.lax.axis_index("model")
-    reduce = (lambda x: jax.lax.psum(x, "model")) if exchange else \
-        (lambda x: x)
-
-    emb = p["tok_emb"]                                  # vocab rows here
     rows = emb.shape[0]
     local = tokens - i * rows
     here = (local >= 0) & (local < rows)
-    x = reduce(jnp.where(here[..., None],
-                         emb[jnp.clip(local, 0, rows - 1)], 0.0))
+    return reduce(jnp.where(here[..., None],
+                            emb[jnp.clip(local, 0, rows - 1)], 0.0))
 
-    def layer(x, lp):
-        h = _rms(x, lp["ln1"], eps)
-        q = _rope(ein("btd,dhk->bthk", h, lp["attn/wq"]), theta)
-        k = _rope(ein("btd,dhk->bthk", h, lp["attn/wk"]), theta)
-        v = ein("btd,dhk->bthk", h, lp["attn/wv"])
-        x = x + reduce(ein("bthk,hkd->btd", _attention(q, k, v, ein),
-                           lp["attn/wo"]))
-        h = _rms(x, lp["ln2"], eps)
-        u = jax.nn.silu(ein("btd,df->btf", h, lp["ffn/wg"])) \
-            * ein("btd,df->btf", h, lp["ffn/wi"])
-        return x + reduce(ein("btf,fd->btd", u, lp["ffn/wo"])), None
 
-    stack = {k[len(STAGE):]: val for k, val in p.items()
-             if k.startswith(STAGE)}
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, stack)
-    x = _rms(x, p["final_norm"], eps)
-    z = ein("btd,dv->btv", x, p["head"])[:, :-1]        # vocab columns here
-    labels = tokens[:, 1:]
+def cross_entropy(z, labels):
+    """Mean cross-entropy of logits ``z`` whose vocabulary columns are split
+    over the ``model`` axis, against ``labels``."""
+    i = jax.lax.axis_index("model")
     m = jax.lax.pmax(jax.lax.stop_gradient(jnp.max(z, axis=-1)), "model")
     lse = m + jnp.log(jax.lax.psum(
         jnp.sum(jnp.exp(z - m[..., None]), axis=-1), "model"))
@@ -201,17 +139,21 @@ def _loss(cfg, p, tokens, ein, exchange):
     return jnp.mean(lse - zy)
 
 
-def grad_fn(cfg: dict, mesh: Mesh, precision="f32", exchange=True):
+def grad_fn(fam, cfg: dict, mesh: Mesh, precision="f32", exchange=True):
     """jit of (float32 params, tokens [m, mb, T]) -> (mean gradient, mean
-    loss) over the ``m`` microbatches, as the trainer accumulates them."""
+    loss) over the ``m`` microbatches, as the trainer accumulates them.
+    ``exchange=False`` leaves the sums across the ``model`` axis out of the
+    family's layers."""
     ein = {"f32": _einsum, "fp8": _einsum_fp8}[precision]
-    pspecs = {n: s[3] for n, s in specs(cfg).items()}
+    pspecs = {n: s[3] for n, s in fam.specs(cfg).items()}
+    reduce = (lambda x: jax.lax.psum(x, "model")) if exchange else \
+        (lambda x: x)
 
     def body(p, tokens):
         def micro(carry, t):
             g, lsum = carry
             loss, gm = jax.value_and_grad(
-                lambda q: _loss(cfg, q, t, ein, exchange))(p)
+                lambda q: fam.loss(cfg, q, t, ein, reduce))(p)
             return (jax.tree.map(jnp.add, g, gm), lsum + loss), None
 
         zeros = jax.tree.map(jnp.zeros_like, p)
@@ -262,45 +204,45 @@ def _to_bf16(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
-def _adam_update(opt, p, m, v, count, lr):
+def _adam_update(opt, decay, p, m, v, count, lr):
     bc1, bc2 = 1 - opt["b1"] ** count, 1 - opt["b2"] ** count
     out = {}
     for n in p:
         step = (m[n] / bc1) / (jnp.sqrt(v[n] / bc2) + opt["eps"])
-        if is_matrix(n):
+        if n in decay:
             step = step + opt["weight_decay"] * p[n]
         out[n] = _to_bf16(p[n] - lr * step)
     return out
 
 
-@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
-def _update1(opt, p, g1, lr):
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=2)
+def _update1(opt, decay, p, g1, lr):
     opt = dict(opt)
     m = {n: (1 - opt["b1"]) * g1[n] for n in g1}
     v = {n: (1 - opt["b2"]) * jnp.square(g1[n]) for n in g1}
-    return _adam_update(opt, p, m, v, 1, lr)
+    return _adam_update(opt, decay, p, m, v, 1, lr)
 
 
-@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
-def _update2(opt, p, g1, g2, lr):
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=2)
+def _update2(opt, decay, p, g1, g2, lr):
     opt = dict(opt)
     b1, b2 = opt["b1"], opt["b2"]
     m = {n: b1 * (1 - b1) * g1[n] + (1 - b1) * g2[n] for n in g1}
     v = {n: b2 * (1 - b2) * jnp.square(g1[n]) + (1 - b2) * jnp.square(g2[n])
          for n in g1}
-    return _adam_update(opt, p, m, v, 2, lr)
+    return _adam_update(opt, decay, p, m, v, 2, lr)
 
 
 def _floats(tree) -> dict:
     return {n: float(x) for n, x in jax.device_get(tree).items()}
 
 
-def initial_weights(cfg: dict, seed: int, mesh: Mesh) -> dict:
+def initial_weights(fam, cfg: dict, seed: int, mesh: Mesh) -> dict:
     """The benchmark's weights for ``seed`` (``bench/weights.py``, the
     values the program starts from), as float32, in this layout."""
     from bench import weights
-    w = weights.make({n: s[:3] for n, s in specs(cfg).items()}, seed,
-                     shardings(cfg, mesh))
+    w = weights.make({n: s[:3] for n, s in fam.specs(cfg).items()}, seed,
+                     shardings(fam, cfg, mesh))
     return _to_f32(w)
 
 
@@ -309,32 +251,35 @@ def _to_f32(tree):
     return {n: x.astype(jnp.float32) for n, x in tree.items()}
 
 
-def run(cfg: dict, mix: dict, seed: int, batches, chips: int, *,
+def run(fam, cfg: dict, mix: dict, seed: int, batches, chips: int, *,
         precision="f32", half=False, exchange=True) -> dict:
-    """Two train steps from the weights of ``seed`` on ``batches`` [2, m,
-    mb, T], over the first ``chips`` devices.  Returns each step's loss,
-    each leaf's norm of the first gradient before and after clipping, and
-    each leaf's change over the two steps.
+    """Two train steps of the family ``fam`` (a module of
+    ``bench/families/``) at the sizes ``cfg``, from the weights of ``seed``
+    on ``batches`` [2, m, mb, T], over the first ``chips`` devices.
+    Returns each step's loss, each leaf's norm of the first gradient before
+    and after clipping, and each leaf's change over the two steps.
 
     ``half`` is a planted fault: each step sees only its first half of the
     microbatches, and takes the mean over those."""
     mesh = make_mesh(chips)
     opt = tuple(sorted(mix["adamw"].items()))
-    grads = grad_fn(cfg, mesh, precision, exchange)
+    decay = tuple(sorted(n for n in fam.specs(cfg) if fam.is_matrix(n)))
+    grads = grad_fn(fam, cfg, mesh, precision, exchange)
     rep = NamedSharding(mesh, P())
     m = batches.shape[1] // 2 if half else batches.shape[1]
     toks = [jax.device_put(np.asarray(b[:m], np.int32), rep) for b in batches]
     with jax.default_matmul_precision("highest"):
-        p = initial_weights(cfg, seed, mesh)
+        p = initial_weights(fam, cfg, seed, mesh)
         g1, l1 = grads(p, toks[0])
         raw = _floats(leaf_norms(g1))
         g1 = _clip(g1, mix["adamw"]["clip"])
         clipped = _floats(leaf_norms(g1))
-        p = _update1(opt, p, g1, lr_at(0, mix))
+        p = _update1(opt, decay, p, g1, lr_at(0, mix))
         g2, l2 = grads(p, toks[1])
         g2 = _clip(g2, mix["adamw"]["clip"])
-        p = _update2(opt, p, g1, g2, lr_at(1, mix))
+        p = _update2(opt, decay, p, g1, g2, lr_at(1, mix))
         del g1, g2
-        change = _floats(diff_norms(p, initial_weights(cfg, seed, mesh)))
+        change = _floats(diff_norms(p, initial_weights(fam, cfg, seed,
+                                                       mesh)))
     return {"loss": [float(l1), float(l2)], "grad_raw": raw,
             "grad": clipped, "change": change}

@@ -6,6 +6,9 @@ A cell is a configuration (``bench/configs/``) under a traffic mix
 (``bench/traffic/``); both, the cell's correctness limits
 (``bench/limits/<cell>.json``) and each per-layer metric's reader
 (``bench/metrics/<metric>.py``) are found by the names in BENCHMARK.json.
+The configuration names its architecture's module
+(``bench/families/<family>.py``): the trainer's config for it, its leaves,
+its loss for the reference and its FLOPs.
 
 Set-up builds the trainer's compiled, donated step (``train.build``) on
 the planned mesh, makes the weights from ``--seed`` on the device, starts
@@ -13,9 +16,10 @@ the trainer's input pipeline, and runs the first two steps, whose results
 are kept for the check.  The window then runs ``train.main``'s per-step
 sequence (next batch, step, blocking read of the loss) for ``--seconds``.
 After it, the program's state is freed and the float32 reference
-(``bench/reference.py``) recomputes the first two steps; ``bench/check.py``
-compares them.  ``--trace 1`` records the window with the profiler and
-reports the per-layer metrics instead of the end-to-end ones.
+(``bench/reference.py`` with the family's loss) recomputes the first two
+steps; ``bench/check.py`` compares them.  ``--trace 1`` records the window
+with the profiler and reports the per-layer metrics instead of the
+end-to-end ones.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced), with
@@ -37,7 +41,7 @@ import sys                                                    # noqa: E402
 import tempfile                                               # noqa: E402
 from dataclasses import dataclass, field                      # noqa: E402
 from pathlib import Path                                      # noqa: E402
-from types import SimpleNamespace                             # noqa: E402
+from types import ModuleType, SimpleNamespace                 # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -63,6 +67,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: ModuleType
     mix: dict
     limits: dict
     end_to_end: list
@@ -79,25 +84,41 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
 
+    config = feed.load(root / conf["file"])
+    if "family" not in config:
+        raise ValueError(f"{conf['file']} names no family: give it "
+                         f"\"family\", a module of bench/families/")
+
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
     return Cell(
-        name=name, chips=w["chips"],
-        config=feed.load(root / conf["file"]),
+        name=name, chips=w["chips"], config=config,
+        family=family(config["family"], root),
         mix=feed.load(root / "bench" / "traffic" / f"{w['traffic']}.json"),
         limits=feed.load(root / "bench" / "limits" / f"{name}.json"),
         end_to_end=mine(bench["end_to_end"]),
         per_layer=mine(bench["per_layer"]), root=root)
 
 
-def metric_reader(cell: Cell, name: str):
-    path = cell.root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load(path: Path, module: str) -> ModuleType:
+    """The module in the file ``path``, loaded by path under the name
+    ``module``."""
+    spec = importlib.util.spec_from_file_location(module, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def family(name: str, root: Path = ROOT) -> ModuleType:
+    """The architecture module ``root/bench/families/<name>.py``."""
+    return _load(root / "bench" / "families" / f"{name}.py",
+                 f"bench_family_{name}")
+
+
+def metric_reader(cell: Cell, name: str):
+    return _load(cell.root / "bench" / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
 
 
 # ----------------------------------------------------------------- device
@@ -120,28 +141,14 @@ def log(msg: str) -> None:
 # ---------------------------------------------------------------- program
 
 def program_config(cell: Cell):
-    """The trainer's config for the cell: the architecture at the file's
-    sizes, as the trainer's own options can state them."""
-    from repro.configs import get_config
-    from repro.launch.train import cut_depth
+    """The trainer's config for the cell, as the cell's family maps its
+    configuration file onto the trainer's options."""
+    return cell.family.trainer_config(cell.config, cell.mix)
 
-    c, mix = cell.config, cell.mix
-    cfg = cut_depth(get_config(c["arch"]), c["num_hidden_layers"]).replace(
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_head=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        act=c["hidden_act"], tie_embeddings=c["tie_word_embeddings"],
-        param_dtype=c["torch_dtype"], compute_dtype=c["torch_dtype"],
-        train_microbatches=mix["microbatches"])
-    departs = [k for k, v in (("family", "dense"), ("optimizer", "adamw"),
-                              ("moe", None), ("mla", None), ("ssm", None),
-                              ("frontend", "none"), ("meta_tokens", 0))
-               if getattr(cfg, k) != v]
-    if departs:
-        raise ValueError(f"{c['arch']}: the trainer's config departs from a "
-                         f"dense decoder in {departs}")
-    return cfg
+
+def flops_per_step(cell: Cell) -> float:
+    """Model FLOPs of one step of the cell, by its family's count."""
+    return cell.family.step_flops(cell.config, cell.mix)
 
 
 def check_recipe(mix: dict) -> None:
@@ -213,7 +220,7 @@ class Trainer:
         from repro.models.layers import ParamSpec
         names, leaves, self.treedef = leaf_names(
             pspecs, lambda x: isinstance(x, ParamSpec))
-        want = reference.specs(cell.config)
+        want = cell.family.specs(cell.config)
         have = {nm: (tuple(s.shape), s.dtype) for nm, s in zip(names, leaves)}
         if have != {nm: (s[0], s[1]) for nm, s in want.items()}:
             raise ValueError(f"the trainer's parameters {have} are not the "
@@ -364,7 +371,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     run = SimpleNamespace(
         steps=len(losses), window_s=window_s, chips=len(devices),
-        flops_per_step=flops.step_flops(cell.config, mix),
+        flops_per_step=flops_per_step(cell),
         peak_flops=peak_flops, input_wait_s=waits, trace=None,
         trace_window=None, trace_window_s=None, busy_s=None,
         step_module="jit_train_step")
@@ -417,7 +424,8 @@ def compare(cell: Cell, seed: int, prog: dict, devices) -> dict:
     mismatch = float(np.sum(seen != stream)) if seen.shape == stream.shape \
         else float(stream.size)
     t0 = time.perf_counter()
-    ref = reference.run(cell.config, cell.mix, seed, stream, len(devices))
+    ref = reference.run(cell.family, cell.config, cell.mix, seed, stream,
+                        len(devices))
     log(f"[reference] two float32 steps in {time.perf_counter() - t0:.3f} s")
     values = {"tokens_mismatch": mismatch}
     for name, (v, what) in check.readings(prog, ref).items():

@@ -1,4 +1,4 @@
-"""The benchmark's FLOP count, its table of peaks, and the shape of
+"""The dense family's FLOP count, the table of peaks, and the shape of
 BENCHMARK.json."""
 import json
 import re
@@ -6,10 +6,11 @@ from pathlib import Path
 
 import pytest
 
-from bench import feed, flops
+from bench import feed, flops, run
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+DENSE = run.family("dense")
 
 
 def load(kind, name):
@@ -21,21 +22,30 @@ def load(kind, name):
     ("deepseek-7b.d8", "pack.s2048.b8", 2.14e14),
 ])
 def test_step_flops(config, mix, want):
-    got = flops.step_flops(load("configs", config), load("traffic", mix))
+    got = DENSE.step_flops(load("configs", config), load("traffic", mix))
     assert got == pytest.approx(want, rel=0.01)
+
+
+@pytest.mark.parametrize("mix,want", [
+    ("pack.s2048.b8", 44427141709824), ("pack.s4096.b4", 46076409151488)])
+def test_yi_step_flops_exactly(mix, want):
+    """``flops_per_step`` of the yi-6b cells, as an integer: the count
+    that ``mfu`` divides."""
+    got = DENSE.step_flops(load("configs", "yi-6b.d1"), load("traffic", mix))
+    assert got == want
 
 
 def test_matmul_params_leave_out_the_embedding():
     yi = load("configs", "yi-6b.d1")
     # 1 layer of 173.0M (q, k, v, o, gated FFN) + the 262.1M LM head
-    assert flops.matmul_params(yi) == 4096 * (4096 * 2 + 512 * 2) \
+    assert DENSE.matmul_params(yi) == 4096 * (4096 * 2 + 512 * 2) \
         + 3 * 4096 * 11008 + 4096 * 64000
 
 
 def test_same_tokens_longer_rows_add_only_attention():
     yi = load("configs", "yi-6b.d1")
-    a = flops.step_flops(yi, load("traffic", "pack.s2048.b8"))
-    b = flops.step_flops(yi, load("traffic", "pack.s4096.b4"))
+    a = DENSE.step_flops(yi, load("traffic", "pack.s2048.b8"))
+    b = DENSE.step_flops(yi, load("traffic", "pack.s4096.b4"))
     assert b - a == pytest.approx(12 * 32 * 128 * (4096 - 2048) * 16384)
 
 

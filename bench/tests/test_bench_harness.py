@@ -82,3 +82,4 @@ def test_every_cell_resolves():
         assert {"tokens_mismatch", "nonfinite_losses"} <= set(cell.limits)
         assert [m["name"] for m in cell.end_to_end] == \
             ["train_tokens_per_s", "setup_s"]
+        assert cell.family.__name__ == f"bench_family_{cell.config['family']}"

@@ -7,6 +7,7 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parents[1]
 
 CONFIG = {"source": "tiny test configuration", "arch": "yi-6b",
+          "family": "dense",
           "hidden_size": 64, "intermediate_size": 128,
           "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
           "num_hidden_layers": 2, "vocab_size": 256,
@@ -31,14 +32,16 @@ LIMITS = {"tokens_mismatch": 0, "nonfinite_losses": 0,
 
 def write(root: Path, metric_src: str = None) -> Path:
     """``root`` holding BENCHMARK.json and ``bench/``: the real metric
-    readers and devices table, tiny configurations, a tiny mix and limits.
+    readers, families and devices table, tiny configurations, a tiny mix
+    and limits.
     ``metric_src`` adds a per-layer metric ``tiny_metric`` with that
     reader's source."""
     b = root / "bench"
-    for d in ("configs", "traffic", "limits", "metrics"):
+    for d in ("configs", "traffic", "limits", "metrics", "families"):
         (b / d).mkdir(parents=True, exist_ok=True)
-    for f in (BENCH / "metrics").glob("*.py"):
-        shutil.copy(f, b / "metrics" / f.name)
+    for d in ("metrics", "families"):
+        for f in (BENCH / d).glob("*.py"):
+            shutil.copy(f, b / d / f.name)
     shutil.copy(BENCH / "devices.json", b / "devices.json")
     (b / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
     (b / "configs" / "tiny4.json").write_text(
